@@ -16,6 +16,7 @@ from pathlib import Path
 
 from benchmarks import (faults, paper_figs, perf, scenarios, serving, shard,
                         tuning)
+from repro.launch.jax_cache import use_compile_cache
 
 BENCHES = [
     ("fig7", paper_figs.fig7_fidelity),
@@ -53,6 +54,7 @@ def main() -> None:
                     help="comma-separated bench-name prefixes")
     args = ap.parse_args()
     only = args.only.split(",") if args.only else None
+    use_compile_cache()
 
     out_path = Path(__file__).resolve().parents[1] / "experiments" \
         / "bench_results.csv"

@@ -7,7 +7,13 @@ vector compare of the requested key against the resident-key arrays held
 entirely in VMEM (for the parameter sweeps cache research needs, C <= a
 few thousand, compare-all beats emulating a hash).  Eviction clock sweeps
 are bounded masked fori_loops (<= 2M iterations), so the kernel has no
-data-dependent control flow — fully TPU-lowerable.
+data-dependent control flow.  It runs in interpret mode only: Mosaic (the
+TPU compiler) refuses it, first at the bool ``argmax`` in ``_lookup``
+("Only float32 is supported"), then at the dynamic lane index
+``trace_ref[:, t]`` (not provably a multiple of 128), then at the bool
+``fori_loop`` carry of ``sweep_insert`` ("failed to legalize operation
+'scf.for'").  No entry point calls it; the device sweep is
+``repro.tuning.sweep`` on the XLA engine step.
 
 State layout per lane block (LANES x slots, int32):
   skey/sref/sseq + spos/seqctr   — Small FIFO ring + correlation window
@@ -81,8 +87,7 @@ def _kernel(trace_ref, skey_ref, sref_ref, sseq_ref, mkey_ref, mref_ref,
         in_m, m_slot = _lookup(mkey, key)
         in_g, g_slot = _lookup(gkey, key)
         hit = in_s | in_m
-        pl.store(hits_ref, (slice(None), pl.dslice(t, 1)),
-                 hit.astype(jnp.int32)[:, None])
+        hits_ref[:, pl.ds(t, 1)] = hit.astype(jnp.int32)[:, None]
 
         # case small-hit: set ref if aged past the correlation window
         age = seqctr - jnp.take_along_axis(sseq, s_slot[:, None], axis=1)[:, 0]

@@ -39,7 +39,7 @@ def replay(trace, state, *, window: int, interpret: bool = False):
 
 def simulate_lanes(traces, capacity: int, *, window_frac: float = 0.5,
                    small_frac: float = 0.1, ghost_frac: float = 0.5,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """traces: (LANES, T) int32 -> (miss_ratios (LANES,), hits (LANES, T))."""
     traces = jnp.asarray(traces, jnp.int32)
     L = traces.shape[0]

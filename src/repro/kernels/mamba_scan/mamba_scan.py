@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _kernel(u_ref, dt_ref, b_ref, c_ref, alog_ref, y_ref, h_ref, *,
             chunk: int, seq_len: int):
@@ -41,10 +39,7 @@ def _kernel(u_ref, dt_ref, b_ref, c_ref, alog_ref, y_ref, h_ref, *,
         dA = jnp.exp(dt[:, None] * A)                    # (d_blk, N)
         h = dA * h + (dt * u)[:, None] * bb[None, :]
         y = jnp.sum(h * cc[None, :], axis=1)             # (d_blk,)
-        # dslice(0, 1) rather than int 0: older pallas interpret-mode
-        # discharge rules reject scalar int indices in store()
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y.astype(y_ref.dtype)[None, None, :])
+        y_ref[0, pl.ds(t, 1), :] = y.astype(y_ref.dtype)[None, :]
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -73,7 +68,7 @@ def mamba_scan_raw(u, dt, Bc, Cc, A_log, *, d_block: int = 512,
         out_specs=pl.BlockSpec((1, chunk, d_block), lambda b, i, c: (b, c, i)),
         out_shape=jax.ShapeDtypeStruct((B, S, din), u.dtype),
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, Bc, Cc, A_log)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -114,7 +112,7 @@ def paged_attention_raw(q, kpool, vpool, block_tables, lengths, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q, kpool, vpool)

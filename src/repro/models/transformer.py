@@ -51,8 +51,11 @@ def block_params(cfg: ModelConfig, rng) -> Dict:
 
 
 def stacked_block_params(cfg: ModelConfig, rng) -> Dict:
+    """Per-layer params stacked on a leading layer axis.  ``lax.map``
+    (not ``vmap``) draws one layer at a time, so the float32 draws that
+    ``dense_init`` casts down never exist for all layers at once."""
     rngs = jax.random.split(rng, cfg.n_layers)
-    return jax.vmap(lambda r: block_params(cfg, r))(rngs)
+    return jax.lax.map(lambda r: block_params(cfg, r), rngs)
 
 
 # -- block application -------------------------------------------------------------

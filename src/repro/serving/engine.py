@@ -179,10 +179,11 @@ class ServingEngine:
         st.out_tokens.append(first)  # from prefill logits
         return first
 
-    def _decode_step(self, ids: List[int]) -> Dict[int, int]:
-        """One decode step for the sequences in ``ids`` (<= max_batch):
-        each sequence's newest token (at position pos) writes its KV at
-        pos and attends to [0, pos].  Returns {req_id: next token}."""
+    def _decode_logits(self, ids: List[int]) -> jnp.ndarray:
+        """One paged decode step for the sequences in ``ids`` (<=
+        max_batch): each sequence's newest token (at position pos) writes
+        its KV at pos and attends to [0, pos].  Returns the next-token
+        logits, (len(ids), vocab)."""
         toks, poss, bts, sids, soffs = [], [], [], [], []
         for rid in ids:
             st = self.mgr.seqs[rid]
@@ -201,14 +202,19 @@ class ServingEngine:
             sids.append(sids[-1])
             soffs.append(soffs[-1])
             bts.append(bts[-1])
-        t_step = time.perf_counter()
         logits, kp, vp = self._decode_fn(
             self.params, jnp.asarray(toks, jnp.int32)[:, None],
             self.pool.kpool, self.pool.vpool,
             jnp.asarray(np.stack(bts)), jnp.asarray(poss, jnp.int32),
             jnp.asarray(sids, jnp.int32), jnp.asarray(soffs, jnp.int32))
         self.pool.kpool, self.pool.vpool = kp, vp
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        return logits[:len(ids), 0]
+
+    def _decode_step(self, ids: List[int]) -> Dict[int, int]:
+        """One greedy decode step for ``ids``.  Returns {req_id: next
+        token}."""
+        t_step = time.perf_counter()
+        nxt = np.asarray(jnp.argmax(self._decode_logits(ids), axis=-1))
         self._h_decode.observe(time.perf_counter() - t_step)
         out = {}
         for i, rid in enumerate(ids):

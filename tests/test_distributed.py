@@ -13,7 +13,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_config, reduced
 from repro.launch.specs import make_batch
 from repro.models.config import ShapeCell
@@ -29,7 +29,8 @@ oc = optim.AdamWConfig(lr=1e-3, warmup_steps=1)
 rc = step_lib.RunConfig(adamw=oc)
 
 def run_on_mesh(shape, state_host=None):
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
     log = rules.RuleLog()
     with mesh:
         params_shape = jax.eval_shape(api.init, jax.random.PRNGKey(0))

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.kvcache.pool import BlockPool
 from repro.models.config import ModelConfig
+from repro.obs.spans import span
 
 _HASH_SPACE = 1 << 48
 
@@ -61,20 +62,21 @@ class PagedKVManager:
         — to the owning serving tenant."""
         n_blocks = -(-len(tokens) // self.bs)
         keys, slots, fill = [], [], []
-        for b in range(n_blocks):
-            end = min((b + 1) * self.bs, len(tokens))
-            full = end == (b + 1) * self.bs
-            if full:
-                key = _prefix_key(tokens[:end])
-            else:
-                key = self._next_handle
-                self._next_handle += 1
-            slot, needs_fill = self.pool.lookup(key, pin=True,
-                                                tenant=tenant)
-            keys.append(key)
-            slots.append(slot)
-            if needs_fill or not full:
-                fill.append(b)
+        with span("repro.kv.admit", rid=seq_id):
+            for b in range(n_blocks):
+                end = min((b + 1) * self.bs, len(tokens))
+                full = end == (b + 1) * self.bs
+                if full:
+                    key = _prefix_key(tokens[:end])
+                else:
+                    key = self._next_handle
+                    self._next_handle += 1
+                slot, needs_fill = self.pool.lookup(key, pin=True,
+                                                    tenant=tenant)
+                keys.append(key)
+                slots.append(slot)
+                if needs_fill or not full:
+                    fill.append(b)
         st = SeqState(seq_id, list(tokens), keys, slots, tenant=tenant)
         self.seqs[seq_id] = st
         return st, fill
@@ -88,9 +90,9 @@ class PagedKVManager:
             key = self._next_handle
             self._next_handle += 1
             slot, _ = self.pool.lookup(key, pin=True, tenant=st.tenant)
-            # contents arrive via write_token in the same step: the block
-            # is immediately usable (leaving it DOING-IO would wedge the
-            # live-resize drain, §4.2)
+            # the same step's decode program writes the token's KV into
+            # it: the block is immediately usable (leaving it DOING-IO
+            # would wedge the live-resize drain, §4.2)
             self.pool.policy.io_done(key)
             self.pool.policy.set_dirty(key)
             st.block_keys.append(key)

@@ -27,6 +27,7 @@ from repro.core.prodcache import EMPTY, ProdClock2QPlus, drive_resize
 from repro.faults import GhostJournal, HostIO, ShardReplicator, splitmix64
 from repro.faults.recovery import failover as _failover
 from repro.models.config import ModelConfig
+from repro.obs.spans import span
 from repro.shardcache import ShardedClock2QPlus
 
 
@@ -102,6 +103,12 @@ class BlockPool:
                                     "HBM<->host block copies")
         self._c_swap_in = swap_fam.labels("in")
         self._c_swap_out = swap_fam.labels("out")
+        self._c_writes = self.obs.counter(
+            "pool_block_writes_total", (), "prefill block writes (two "
+            "eager whole-pool updates each)").labels()
+        self._c_flushes = self.obs.counter(
+            "pool_flushes_total", (), "flushes that copied a dirty block "
+            "to host (two blocking device->host copies each)").labels()
         self._c_drop = self.obs.counter(
             "pool_drops_total", (), "clean evictions (host copy "
             "already existed)").labels()
@@ -267,7 +274,8 @@ class BlockPool:
         did not happen (IO gave up, breaker shed, or the host copy was
         quarantined) — the caller serves the miss read-through."""
         if self._io is None:
-            self._copy_in(key, slot)
+            with span("repro.kv.swap_in"):
+                self._copy_in(key, slot)
             self._c_swap_in.value += 1
             return True
         if key in self._corrupt:
@@ -277,7 +285,9 @@ class BlockPool:
             self._corrupt.discard(key)
             self._c_corrupt.value += 1
             return False
-        res = self._io.run("swap_in", key, lambda: self._copy_in(key, slot))
+        with span("repro.kv.swap_in"):
+            res = self._io.run("swap_in", key,
+                               lambda: self._copy_in(key, slot))
         if not res.ok:
             return False
         self._c_swap_in.value += 1
@@ -288,15 +298,10 @@ class BlockPool:
         """k/v: (L, block_size, H, hd) — fill a block after prefill."""
         self.kpool = self.kpool.at[:, slot].set(k)
         self.vpool = self.vpool.at[:, slot].set(v)
+        self._c_writes.value += 1
         if key is not None:
             self.policy.io_done(key)
             self.policy.set_dirty(key)  # HBM-only content until flushed
-
-    def write_token(self, slot: int, offset: int, k: jnp.ndarray,
-                    v: jnp.ndarray) -> None:
-        """k/v: (L, H, hd) — append one decoded token into a block."""
-        self.kpool = self.kpool.at[:, slot, offset].set(k)
-        self.vpool = self.vpool.at[:, slot, offset].set(v)
 
     def unpin(self, key: int) -> None:
         self.policy.unpin(key)
@@ -321,13 +326,15 @@ class BlockPool:
             else:
                 self._copy_out(key, slot)
             self._c_swap_out.value += 1
+            self._c_flushes.value += 1
         self.policy.clean(key)
 
     def run_flusher(self, max_blocks: int = 4) -> int:
         """Watermark flusher (paper §4.1.3): mirror oldest dirty blocks."""
-        dirty = self.policy.dirty_keys()[:max_blocks]
-        for k in dirty:
-            self.flush(k)
+        with span("repro.kv.flush"):
+            dirty = self.policy.dirty_keys()[:max_blocks]
+            for k in dirty:
+                self.flush(k)
         return len(dirty)
 
     # -- backpressure (serving scheduler) -----------------------------------------

@@ -34,6 +34,7 @@ from repro.kvcache.manager import PagedKVManager
 from repro.kvcache.pool import BlockPool
 from repro.models import transformer as T
 from repro.models.model import ModelAPI
+from repro.obs.spans import span
 from repro.serving.admission import ST_COMPLETED, SchedRequest
 from repro.serving.scheduler import SchedConfig, Scheduler
 
@@ -123,15 +124,20 @@ class ServingEngine:
         self._g_pending = depth_fam.labels("pending")
         self._g_active = depth_fam.labels("active")
         self._admit_ts: Dict[int, float] = {}
-        self._decode_fn = jax.jit(
-            lambda params, toks, kp, vp, bt, lens, sid, soff:
-            T.forward_decode_paged(api.cfg, params, toks, kp, vp, bt, lens,
-                                   sid, soff))
+
+        # named, so the device trace shows jit_decode_step/jit_prefill_step
+        def decode_step(params, toks, kp, vp, bt, lens, sid, soff):
+            return T.forward_decode_paged(api.cfg, params, toks, kp, vp, bt,
+                                          lens, sid, soff)
+
         # prompts are padded to block_size buckets so prefill compiles
         # once per bucket, not once per prompt length
-        self._prefill_fn = jax.jit(
-            lambda params, batch: T.forward_prefill(api.cfg, params, batch,
-                                                    full_logits=True))
+        def prefill_step(params, batch):
+            return T.forward_prefill(api.cfg, params, batch,
+                                     full_logits=True)
+
+        self._decode_fn = jax.jit(decode_step)
+        self._prefill_fn = jax.jit(prefill_step)
 
     # -- prefill ------------------------------------------------------------------
     def _prefill_into_pool(self, st, fill_blocks: List[int]) -> int:
@@ -143,20 +149,24 @@ class ServingEngine:
         n_real = len(st.tokens)
         pad = (-n_real) % self.pool.bs  # length bucketing (one compile
         toks = list(st.tokens) + [0] * pad  # per bucket, not per length)
-        toks = jnp.asarray(toks, jnp.int32)[None]
-        logits, cache = self._prefill_fn(self.params, {"tokens": toks})
+        with span("repro.model.prefill"):
+            toks = jnp.asarray(toks, jnp.int32)[None]
+            logits, cache = self._prefill_fn(self.params, {"tokens": toks})
         bs = self.pool.bs
-        k = cache.k[:, 0]  # (L, S, H, hd)
-        v = cache.v[:, 0]
-        for b in fill_blocks:
-            lo, hi = b * bs, min((b + 1) * bs, len(st.tokens))
-            kb = jnp.zeros((self.cfg.n_layers, bs, self.cfg.n_kv_heads,
-                            self.cfg.hd), k.dtype)
-            kb = kb.at[:, :hi - lo].set(k[:, lo:hi])
-            vb = jnp.zeros_like(kb)
-            vb = vb.at[:, :hi - lo].set(v[:, lo:hi])
-            self.pool.write_block(st.slots[b], kb, vb, key=st.block_keys[b])
-        return int(jnp.argmax(logits[0, n_real - 1]))
+        with span("repro.kv.write_blocks"):
+            k = cache.k[:, 0]  # (L, S, H, hd)
+            v = cache.v[:, 0]
+            for b in fill_blocks:
+                lo, hi = b * bs, min((b + 1) * bs, len(st.tokens))
+                kb = jnp.zeros((self.cfg.n_layers, bs, self.cfg.n_kv_heads,
+                                self.cfg.hd), k.dtype)
+                kb = kb.at[:, :hi - lo].set(k[:, lo:hi])
+                vb = jnp.zeros_like(kb)
+                vb = vb.at[:, :hi - lo].set(v[:, lo:hi])
+                self.pool.write_block(st.slots[b], kb, vb,
+                                      key=st.block_keys[b])
+        with span("repro.engine.sample"):
+            return int(jnp.argmax(logits[0, n_real - 1]))
 
     # -- execution primitives (what the scheduler drives) ------------------------
     def _max_seq_blocks(self) -> int:
@@ -174,8 +184,9 @@ class ServingEngine:
     def _start(self, r: Request, tenant: str = "default") -> int:
         """Admit + prefill one request; returns its first token."""
         self._admit_ts[r.req_id] = time.perf_counter()
-        st, fill = self.mgr.admit(r.req_id, r.prompt, tenant=tenant)
-        first = self._prefill_into_pool(st, fill)
+        with span("repro.engine.prefill", rid=r.req_id):
+            st, fill = self.mgr.admit(r.req_id, r.prompt, tenant=tenant)
+            first = self._prefill_into_pool(st, fill)
         st.out_tokens.append(first)  # from prefill logits
         return first
 
@@ -184,44 +195,50 @@ class ServingEngine:
         max_batch): each sequence's newest token (at position pos) writes
         its KV at pos and attends to [0, pos].  Returns the next-token
         logits, (len(ids), vocab)."""
-        toks, poss, bts, sids, soffs = [], [], [], [], []
-        for rid in ids:
-            st = self.mgr.seqs[rid]
-            pos = st.length - 1           # position of the token processed
-            toks.append(st.out_tokens[-1])
-            poss.append(pos)
-            slot, off = self.mgr.slot_for_pos(rid, pos)
-            sids.append(slot)
-            soffs.append(off)
-            bts.append(self.mgr.block_table(rid, self.max_blocks))
-        # pad to max_batch (one compile for all batch sizes); padded
-        # rows duplicate the last row — they rewrite identical values
-        while len(toks) < self.max_batch:
-            toks.append(toks[-1])
-            poss.append(poss[-1])
-            sids.append(sids[-1])
-            soffs.append(soffs[-1])
-            bts.append(bts[-1])
-        logits, kp, vp = self._decode_fn(
-            self.params, jnp.asarray(toks, jnp.int32)[:, None],
-            self.pool.kpool, self.pool.vpool,
-            jnp.asarray(np.stack(bts)), jnp.asarray(poss, jnp.int32),
-            jnp.asarray(sids, jnp.int32), jnp.asarray(soffs, jnp.int32))
+        with span("repro.engine.decode_inputs"):
+            toks, poss, bts, sids, soffs = [], [], [], [], []
+            for rid in ids:
+                st = self.mgr.seqs[rid]
+                pos = st.length - 1       # position of the token processed
+                toks.append(st.out_tokens[-1])
+                poss.append(pos)
+                slot, off = self.mgr.slot_for_pos(rid, pos)
+                sids.append(slot)
+                soffs.append(off)
+                bts.append(self.mgr.block_table(rid, self.max_blocks))
+            # pad to max_batch (one compile for all batch sizes); padded
+            # rows duplicate the last row — they rewrite identical values
+            while len(toks) < self.max_batch:
+                toks.append(toks[-1])
+                poss.append(poss[-1])
+                sids.append(sids[-1])
+                soffs.append(soffs[-1])
+                bts.append(bts[-1])
+            args = (jnp.asarray(toks, jnp.int32)[:, None],
+                    self.pool.kpool, self.pool.vpool,
+                    jnp.asarray(np.stack(bts)), jnp.asarray(poss, jnp.int32),
+                    jnp.asarray(sids, jnp.int32),
+                    jnp.asarray(soffs, jnp.int32))
+        with span("repro.model.decode"):
+            logits, kp, vp = self._decode_fn(self.params, *args)
         self.pool.kpool, self.pool.vpool = kp, vp
         return logits[:len(ids), 0]
 
     def _decode_step(self, ids: List[int]) -> Dict[int, int]:
         """One greedy decode step for ``ids``.  Returns {req_id: next
         token}."""
-        t_step = time.perf_counter()
-        nxt = np.asarray(jnp.argmax(self._decode_logits(ids), axis=-1))
-        self._h_decode.observe(time.perf_counter() - t_step)
-        out = {}
-        for i, rid in enumerate(ids):
-            tok = int(nxt[i])
-            self.mgr.seqs[rid].out_tokens.append(tok)
-            out[rid] = tok
-        self.mgr.maintenance()
+        with span("repro.engine.decode", n=len(ids)):
+            t_step = time.perf_counter()
+            logits = self._decode_logits(ids)
+            with span("repro.engine.sample"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            self._h_decode.observe(time.perf_counter() - t_step)
+            out = {}
+            for i, rid in enumerate(ids):
+                tok = int(nxt[i])
+                self.mgr.seqs[rid].out_tokens.append(tok)
+                out[rid] = tok
+            self.mgr.maintenance()
         return out
 
     def _finish(self, rid: int) -> Completion:

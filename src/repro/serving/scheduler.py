@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from repro import obs as obs_mod
 from repro.faults.io import Clock
 from repro.faults.plan import splitmix64
+from repro.obs.spans import span
 from repro.serving.admission import (
     R_DEADLINE, R_DEGRADED, R_DISPLACED, R_OVERSIZE, ST_COMPLETED,
     ST_REJECTED, ST_SHED, AdmissionConfig, AdmissionQueue, SchedRequest,
@@ -187,6 +188,10 @@ class Scheduler:
         a token-budgeted batch (prefills + one decode step for the
         previously-active sequences), dispatch it, advance the clock.
         Returns the number of sequences that completed this tick."""
+        with span("repro.sched.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
         now = self.clock.now
         adm = self.cfg.admission
         # 1. SLO shedding: anything that cannot finish in time anymore

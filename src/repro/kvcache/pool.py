@@ -46,6 +46,14 @@ class PoolStats:
         return self.hits / max(1, self.hits + self.misses)
 
 
+@jax.jit
+def _gather_slots(kpool, vpool, slots):
+    """The K and V blocks at ``slots``, slot-major ``(n, L, bs, H, hd)``,
+    so each block is one contiguous row on the host."""
+    return (jnp.moveaxis(kpool[:, slots], 1, 0),
+            jnp.moveaxis(vpool[:, slots], 1, 0))
+
+
 class BlockPool:
     """Fixed HBM pool of KV blocks + host tier, Clock2Q+ replacement."""
 
@@ -108,7 +116,12 @@ class BlockPool:
             "eager whole-pool updates each)").labels()
         self._c_flushes = self.obs.counter(
             "pool_flushes_total", (), "flushes that copied a dirty block "
-            "to host (two blocking device->host copies each)").labels()
+            "to host (batched per flusher pass on the unhardened path, "
+            "two blocking device->host copies each under faults=)").labels()
+        self._c_flush_batches = self.obs.counter(
+            "pool_flush_batches_total", (), "device->host transfers the "
+            "flusher made (one per pass that copied a block; unhardened "
+            "path)").labels()
         self._c_drop = self.obs.counter(
             "pool_drops_total", (), "clean evictions (host copy "
             "already existed)").labels()
@@ -330,12 +343,53 @@ class BlockPool:
         self.policy.clean(key)
 
     def run_flusher(self, max_blocks: int = 4) -> int:
-        """Watermark flusher (paper §4.1.3): mirror oldest dirty blocks."""
+        """Watermark flusher (paper §4.1.3): mirror oldest dirty blocks.
+        The hardened path flushes key by key (each copy is one retried
+        ``HostIO`` operation); the unhardened path copies the pass's
+        blocks in one device->host transfer (``_flush_batch``)."""
         with span("repro.kv.flush"):
             dirty = self.policy.dirty_keys()[:max_blocks]
-            for k in dirty:
-                self.flush(k)
+            if self._io is not None:
+                for k in dirty:
+                    self.flush(k)
+            else:
+                self._flush_batch(dirty, max_blocks)
         return len(dirty)
+
+    def _flush_batch(self, dirty, max_blocks: int) -> None:
+        """``flush`` over ``dirty`` with the copies gathered into one
+        program and one fetch.  The same keys are copied, counted and
+        cleaned as key-by-key flushing would: a non-resident key is
+        skipped, a mirrored one only cleaned, and copies decided earlier
+        in the pass count against the host tier's room.  Each block is
+        cleaned after its host copy exists, in the same pass."""
+        room = self.n_host_blocks - len(self.host)
+        resident, copy, slots = [], [], []
+        for key in dirty:
+            slot = self.policy.slot_of(key)
+            if slot == EMPTY:
+                continue
+            resident.append(key)
+            if key not in self.host and len(copy) < room:
+                copy.append(key)
+                slots.append(slot)
+        if copy:
+            # padded with a repeat of a real slot, so one program serves
+            # every pass of up to max_blocks copies
+            idx = np.full(max_blocks, slots[-1], np.int32)
+            idx[:len(slots)] = slots
+            kb, vb = jax.device_get(_gather_slots(self.kpool, self.vpool,
+                                                  idx))
+            if len(copy) < max_blocks:
+                # the host rows are views: keep no padded row alive
+                kb, vb = kb[:len(copy)].copy(), vb[:len(copy)].copy()
+            for i, key in enumerate(copy):
+                self.host[key] = (kb[i], vb[i])
+            self._c_flush_batches.value += 1
+            self._c_swap_out.value += len(copy)
+            self._c_flushes.value += len(copy)
+        for key in resident:
+            self.policy.clean(key)
 
     # -- backpressure (serving scheduler) -----------------------------------------
     def pinned_count(self) -> int:

@@ -6,19 +6,26 @@ The harness builds the served path the way ``repro.launch.serve`` does
 clock, and wraps the executor's ``prefill``/``decode``/``release`` in
 host spans (``bench.<kind>`` trace annotations) that time every call.
 Everything the per-layer metrics read is gathered in a ``RunData``.
+
+What depends on the architecture comes from the configuration's family,
+``families/<family>.py`` (``family``): the program's ``ModelConfig``,
+the weight layout, the work a call requires, and the reference.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import heapq
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import time
+import types
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -48,13 +55,16 @@ class Cell:
     mix: Dict             # the traffic mix file
     limits: Dict          # the cell's limits file
     metrics: Dict[str, Dict]    # name -> BENCHMARK.json entry (+ "reader")
+    family: types.ModuleType    # families/<the configuration's family>.py
 
     @classmethod
     def load(cls, root: Path, bench_dir: Path, workload: str) -> "Cell":
         """The cell ``workload`` of ``root/BENCHMARK.json``: its
-        configuration file, its mix ``traffic/<mix>.json``, its limits
-        ``cells/<workload>.json`` and a reader ``metrics/<metric>.py``
-        for each metric it reports."""
+        configuration file and that file's family
+        ``families/<family>.py`` (``dense`` where it names none), its
+        mix ``traffic/<mix>.json``, its limits ``cells/<workload>.json``
+        and a reader ``metrics/<metric>.py`` for each metric it
+        reports."""
         spec = json.loads((root / "BENCHMARK.json").read_text())
         cells = {w["name"]: w for w in spec["workloads"]}
         if workload not in cells:
@@ -72,19 +82,41 @@ class Cell:
                 if workload in m.get("workloads", [workload]):
                     metrics[m["name"]] = dict(m, group=group, reader=_reader(
                         bench_dir / "metrics" / f"{m['name']}.py"))
-        return cls(workload, w["chips"], conf, mix, limits, metrics)
+        return cls(workload, w["chips"], conf, mix, limits, metrics,
+                   family(bench_dir, conf.get("family", "dense")))
 
     def reported(self, trace: bool) -> Dict[str, Dict]:
         group = "per_layer" if trace else "end_to_end"
         return {k: m for k, m in self.metrics.items() if m["group"] == group}
 
 
+def family(bench_dir: Path, name: str) -> types.ModuleType:
+    """The architecture file ``families/<name>.py``.  It provides
+    ``model_config(conf)``, ``Shapes.of(conf)`` (with at least
+    ``vocab``), ``prefill_work(s, positions)``, ``decode_work(s,
+    contexts)``, ``weight_shapes(s)``, ``program_params(w, s, expected)``
+    and ``logits_at(w, conf, seqs, rows, *, pad_to, mode)``."""
+    path = bench_dir / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"family {name!r} has no file {path}")
+    return _module(path, "chipbench_family_")
+
+
 def _reader(path: Path) -> Callable:
-    mod_name = "chipbench_metric_" + path.stem.replace(".", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
+    return _module(path, "chipbench_metric_").read
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path, prefix: str) -> types.ModuleType:
+    """The module of a file under the benchmark, loaded once a process
+    and registered under a name of its own path (a dataclass in it looks
+    its module up there)."""
+    name = prefix + re.sub(r"\W", "_", str(path))
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 # -- what a run records -------------------------------------------------------
@@ -107,6 +139,7 @@ class Call:
     t1: float
     flops: int
     nbytes: int
+    lengths: Tuple[int, ...]      # positions computed | rows' contexts
 
 
 @dataclasses.dataclass
@@ -164,11 +197,13 @@ def percentile(values, q: float) -> Optional[float]:
 class Spans:
     """Wraps the scheduler's executor: each call runs inside a
     ``bench.<kind>`` trace annotation and is timed on the host clock,
-    with the work it requires counted from the true lengths."""
+    with the work it requires counted by the family from the true
+    lengths."""
 
-    def __init__(self, inner, eng, shapes: work.Shapes,
+    def __init__(self, inner, eng, fam: types.ModuleType, shapes,
                  records: Dict[int, ReqRecord]):
         self.inner = inner
+        self.fam = fam
         self.shapes = shapes
         self.records = records
         self.calls: List[Call] = []
@@ -197,13 +232,14 @@ class Spans:
         pos = {p for b in self._fill
                for p in range(b * self.block, min((b + 1) * self.block, n))}
         pos.add(n - 1)
+        pos = tuple(sorted(pos))
         self.calls.append(Call("prefill", t0, t1,
-                               *work.prefill_work(self.shapes, pos)))
+                               *self.fam.prefill_work(self.shapes, pos), pos))
         return tok
 
     def decode(self, ids):
-        ctx = [len(self.records[i].prompt) + len(self.records[i].token_times)
-               for i in ids]
+        ctx = tuple(len(self.records[i].prompt)
+                    + len(self.records[i].token_times) for i in ids)
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.decode"):
             out = self.inner.decode(ids)
@@ -211,7 +247,7 @@ class Spans:
         for i in ids:
             self.records[i].token_times.append(t1)
         self.calls.append(Call("decode", t0, t1,
-                               *work.decode_work(self.shapes, ctx)))
+                               *self.fam.decode_work(self.shapes, ctx), ctx))
         return out
 
     def release(self, rid):
@@ -278,30 +314,6 @@ def served_dtype(conf: Dict) -> str:
     return conf["serving"]["dtype"]
 
 
-def model_config(conf: Dict):
-    """The program's ``ModelConfig`` at the configuration file's sizes,
-    refusing a file that states what the program would not run."""
-    from repro.launch import serve
-
-    cfg = serve.model_config(conf["arch"], conf["num_hidden_layers"])
-    cfg = dataclasses.replace(
-        cfg, n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or 0, d_ff=conf["intermediate_size"],
-        vocab=conf["vocab_size"], dtype=served_dtype(conf),
-        param_dtype=served_dtype(conf))
-    stated = {"norm": conf["norm"], "tie_embeddings":
-              conf["tie_word_embeddings"], "rope_theta": conf["rope_theta"],
-              "act": "swiglu" if conf["hidden_act"] == "silu" else "?",
-              "family": "dense", "qkv_bias": False, "rope_frac": 1.0}
-    runs = {k: getattr(cfg, k) for k in stated}
-    if runs != stated:
-        raise ValueError(f"{conf['name']}: the program runs {runs}, the "
-                         f"configuration file states {stated}")
-    return cfg
-
-
 @dataclasses.dataclass
 class Served:
     """The state of the served path during a run."""
@@ -312,16 +324,17 @@ class Served:
     records: Dict[int, ReqRecord]
 
 
-def build_served(conf: Dict, shapes: work.Shapes, w: Dict) -> Served:
+def build_served(fam: types.ModuleType, conf: Dict, shapes, w: Dict
+                 ) -> Served:
     from repro.launch import serve
     from repro.models.model import build
     from repro.serving.engine import ServingEngine
     from repro.serving.scheduler import SchedConfig
 
-    cfg = model_config(conf)
+    cfg = fam.model_config(conf)
     api = build(cfg)
     expected = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    params = weights.program_params(w, shapes, expected)
+    params = fam.program_params(w, shapes, expected)
     jax.block_until_ready(params)
     sv = conf["serving"]
     blocks = sv["hbm_blocks"] or serve.fit_pool_blocks(cfg, sv["block_size"])
@@ -331,7 +344,7 @@ def build_served(conf: Dict, shapes: work.Shapes, w: Dict) -> Served:
     sched = eng.make_scheduler(config=SchedConfig(
         token_budget=sv["token_budget"], max_batch=sv["max_batch"]))
     records: Dict[int, ReqRecord] = {}
-    spans = Spans(sched.x, eng, shapes, records)
+    spans = Spans(sched.x, eng, fam, shapes, records)
     sched.x = spans
     return Served(eng, sched, spans, records)
 
@@ -549,16 +562,17 @@ def sample_finished(sv: Served, w0: float, n: int, seed: int
     return [(done[i][0].prompt, list(done[i][1])) for i in pick]
 
 
-def check(w: Dict, conf: Dict, sample, pad_to: int, mode: str = "f32"
+def check(cell: Cell, w: Dict, sample, mode: str = "f32"
           ) -> Dict[str, float]:
-    """The gaps of the served tokens below the reference's best
-    (``reference.gaps``); with another ``mode``, of the tokens that the
-    control in that mode puts first."""
+    """The gaps of the served tokens below the reference's best (the
+    family's ``logits_at``, ``reference.gaps``); with another ``mode``,
+    of the tokens that the control in that mode puts first."""
     seqs, rows, toks = reference.served_rows(*zip(*sample))
-    ref = reference.logits_at(w, conf, seqs, rows, pad_to=pad_to)
+    pad_to = cell.mix["max_tokens"]
+    ref = cell.family.logits_at(w, cell.conf, seqs, rows, pad_to=pad_to)
     if mode != "f32":
-        ctl = reference.logits_at(w, conf, seqs, rows, pad_to=pad_to,
-                                  mode=mode)
+        ctl = cell.family.logits_at(w, cell.conf, seqs, rows, pad_to=pad_to,
+                                    mode=mode)
         toks = np.asarray(jax.numpy.argmax(ctl, axis=-1))
     return reference.gaps(ref, toks)
 
@@ -579,12 +593,14 @@ def judge(gaps: Optional[Dict[str, float]], wrong_len: int, limits: Dict
 
 @dataclasses.dataclass
 class Outcome:
-    """A finished run: the result line and what the check compared."""
+    """A finished run: the result line, what the check compared, and the
+    executor's calls with the work each required."""
 
     result: Dict
     sample: List[Tuple[np.ndarray, List[int]]]
     weights: Dict
     notes: Dict
+    calls: List[Call]
 
 
 def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
@@ -601,9 +617,10 @@ def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
     conf, mix, sv_conf = cell.conf, cell.mix, cell.conf["serving"]
     if trace:
         seconds = min(seconds, TRACE_WINDOW_S)
-    shapes = work.Shapes.of(conf)
-    w = weights.make(shapes, seed, served_dtype(conf))
-    sv = build_served(conf, shapes, w)
+    shapes = cell.family.Shapes.of(conf)
+    w = weights.make(cell.family.weight_shapes(shapes), seed,
+                     served_dtype(conf))
+    sv = build_served(cell.family, conf, shapes, w)
     block = sv_conf["block_size"]
     clients = traffic.generate(mix, vocab=shapes.vocab, seed=seed,
                                block=block)
@@ -652,7 +669,7 @@ def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
                  s == "completed" for s in statuses.values())}
     del sv, clients
     gc.collect()
-    gaps = check(w, conf, sample, mix["max_tokens"]) if sample else None
+    gaps = check(cell, w, sample) if sample else None
     correct, checks = judge(gaps, wrong_len, cell.limits)
     notes["gaps"] = gaps
     metrics = {}
@@ -673,7 +690,8 @@ def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
         notes["trace_shift_s"] = reduced.shift_s
         notes["device_s"] = reduced.device_s
     result["checks"] = checks
-    return Outcome(result, sample, w if keep_weights else {}, notes)
+    return Outcome(result, sample, w if keep_weights else {}, notes,
+                   data.calls)
 
 
 def print_result(out: Outcome) -> None:

@@ -1,8 +1,10 @@
-"""The plain reference: the configuration's forward pass in float32 with
-matrix products at full precision, straightforward ``jax.numpy``, one
-layer at a time over whole sequences.  No KV cache, no paging, no
-batching across requests beyond padding.  It imports nothing of the
-program and reads only the benchmark's own weights.
+"""What every family's plain reference shares.  A family's ``logits_at``
+(``families/<family>.py``) is the configuration's forward pass in
+float32 with matrix products at full precision (``_mm``),
+straightforward ``jax.numpy``, one layer at a time over whole
+sequences.  No KV cache, no paging, no batching across requests beyond
+padding.  It imports nothing of the program and reads only the
+benchmark's own weights.
 
 The controls are the same pass one precision step below the one the
 configuration serves in (``CONTROL``), which a cell's limits are set to
@@ -14,7 +16,6 @@ with activations in bfloat16; for float32, matrix products in bfloat16
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -38,10 +39,11 @@ def _mm(x, w, mode: str):
     scale = jnp.max(jnp.abs(w32), axis=0, keepdims=True) / top
     scale = jnp.where(scale == 0, 1.0, scale)
     if mode == "int8":
-        wq = jnp.clip(jnp.round(w32 / scale), -127, 127)
+        rounded = jnp.clip(jnp.round(w32 / scale), -127, 127)
     else:
-        wq = (w32 / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32)
-    return jnp.matmul(x.astype(jnp.bfloat16), (wq * scale).astype(jnp.bfloat16),
+        rounded = (w32 / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32)
+    return jnp.matmul(x.astype(jnp.bfloat16),
+                      (rounded * scale).astype(jnp.bfloat16),
                       preferred_element_type=jnp.float32)
 
 
@@ -66,76 +68,6 @@ def _rope(x, theta: float):
     sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(x, lw: Dict, conf: Dict, mode: str):
-    B, S, D = x.shape
-    H, Hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf.get("head_dim") or D // H
-    res = conf["residual_multiplier"]
-    h = _norm(x, lw.get("attn_norm"), conf)
-    q = _rope(_mm(h, lw["wq"], mode).reshape(B, S, H, hd),
-              conf["rope_theta"])
-    k = _rope(_mm(h, lw["wk"], mode).reshape(B, S, Hkv, hd),
-              conf["rope_theta"])
-    v = _mm(h, lw["wv"], mode).reshape(B, S, Hkv, hd)
-    k = jnp.repeat(k, H // Hkv, axis=2)
-    v = jnp.repeat(v, H // Hkv, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST)
-    s = s * conf["attention_multiplier"]
-    causal = np.tril(np.ones((S, S), bool))
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST)
-    x = x + res * _mm(o.reshape(B, S, H * hd), lw["wo"], mode)
-    h = _norm(x, lw.get("mlp_norm"), conf)
-    m = jax.nn.silu(_mm(h, lw["w_gate"], mode)) * _mm(h, lw["w_up"], mode)
-    return x + res * _mm(m, lw["w_down"], mode)
-
-
-_LAYER_KEYS = ("attn_norm", "mlp_norm", "wq", "wk", "wv", "wo", "w_gate",
-               "w_up", "w_down")
-
-
-@functools.partial(jax.jit, static_argnames=("conf_items", "mode"))
-def _layer_jit(x, lw, conf_items, mode):
-    return _layer(x, lw, dict(conf_items), mode)
-
-
-@functools.partial(jax.jit, static_argnames=("conf_items", "mode"))
-def _head_jit(x, rows_b, rows_s, w, conf_items, mode):
-    conf = dict(conf_items)
-    h = _norm(x[rows_b, rows_s], w.get("final_norm"), conf)
-    head = w["head"] if "head" in w else w["embed"].T
-    return _mm(h, head, mode) / conf["logits_scaling"]
-
-
-def _conf_items(conf: Dict) -> Tuple:
-    keys = ("norm", "rms_norm_eps", "layer_norm_eps", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "rope_theta",
-            "residual_multiplier", "attention_multiplier", "logits_scaling")
-    return tuple((k, conf.get(k)) for k in keys)
-
-
-def logits_at(w: Dict, conf: Dict, seqs: Sequence[np.ndarray],
-              rows: Sequence[Tuple[int, int]], *, pad_to: int,
-              mode: str = "f32") -> jax.Array:
-    """Logits, (len(rows), vocab) float32, at each (sequence, position)
-    of ``rows``.  Sequences are padded at the end to ``pad_to`` tokens,
-    which causal attention leaves without effect."""
-    toks = np.zeros((len(seqs), pad_to), np.int32)
-    for i, s in enumerate(seqs):
-        toks[i, :len(s)] = s
-    items = _conf_items(conf)
-    x = jnp.take(w["embed"], jnp.asarray(toks), axis=0).astype(jnp.float32)
-    x = x * conf["embedding_multiplier"]
-    for layer in range(conf["num_hidden_layers"]):
-        lw = {k: w[k][layer] for k in _LAYER_KEYS if k in w}
-        x = _layer_jit(x, lw, items, mode)
-    rb = jnp.asarray([r[0] for r in rows], jnp.int32)
-    rs = jnp.asarray([r[1] for r in rows], jnp.int32)
-    hw = {k: w[k] for k in ("final_norm", "head", "embed") if k in w}
-    return _head_jit(x, rb, rs, hw, items, mode)
 
 
 def served_rows(prompts: Sequence[np.ndarray], served: Sequence[List[int]]

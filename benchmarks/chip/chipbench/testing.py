@@ -1,13 +1,14 @@
 """A benchmark tree at a size a CPU test run holds: a copy of this
 benchmark with one tiny configuration and two tiny mixes added as
-files and entries, the way a later change adds a cell."""
+files and entries, the way a later change adds a cell, and any
+architecture families added the same way."""
 
 from __future__ import annotations
 
 import json
 import shutil
 from pathlib import Path
-from typing import Tuple
+from typing import Dict, Optional, Tuple
 
 from chipbench import harness
 
@@ -48,30 +49,45 @@ TINY_LIMITS = {"max_logit_gap": 0.002, "mean_logit_gap": 0.00002,
                "sample_requests": 8}
 
 
-def tiny_tree(dest: Path) -> Tuple[Path, Path]:
+def tiny_tree(dest: Path, families: Optional[Dict[str, Tuple[str, Dict]]]
+              = None) -> Tuple[Path, Path]:
     """(root, bench_dir) of a copy of the benchmark under ``dest`` with
-    cells ``tiny.<mix>`` added for each of ``TINY_MIXES``."""
+    cells ``tiny.<mix>`` added for each of ``TINY_MIXES``.  ``families``
+    maps a family's name to the source of its file and the keys its
+    configuration changes in the tiny one; each is added as
+    ``families/<name>.py``, a configuration ``tiny_<name>`` that names
+    it, and cells ``tiny_<name>.<mix>``."""
     src_root, src_bench = harness.bench_paths()
     root = Path(dest)
     bench = root / "benchmarks" / "chip"
     shutil.copytree(src_bench, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "test_*"))
     spec = json.loads((src_root / "BENCHMARK.json").read_text())
-    base = json.loads((bench / "configs" / "granite-3-8b.json").read_text())
-    (bench / "configs" / "tiny.json").write_text(
-        json.dumps(dict(base, **TINY_CONF)))
-    spec["configs"].append({"name": "tiny", "source": "tiny test copy",
-                            "file": "benchmarks/chip/configs/tiny.json",
-                            "reduced": [], "why": "CPU tests"})
     for mix, params in TINY_MIXES.items():
         (bench / "traffic" / f"{mix}.json").write_text(json.dumps(params))
-        name = f"tiny.{mix}"
-        spec["workloads"].append({"name": name, "config": "tiny",
-                                  "traffic": mix, "chips": 1,
-                                  "why": "CPU tests"})
-        (bench / "cells" / f"{name}.json").write_text(json.dumps(TINY_LIMITS))
-        for m in spec["end_to_end"] + spec["per_layer"]:
-            if "workloads" in m:
-                m["workloads"].append(name)
+
+    def add(conf: Dict) -> None:
+        name = conf["name"]
+        (bench / "configs" / f"{name}.json").write_text(json.dumps(conf))
+        spec["configs"].append({"name": name, "source": "tiny test copy",
+                                "file": f"benchmarks/chip/configs/{name}.json",
+                                "reduced": [], "why": "CPU tests"})
+        for mix in TINY_MIXES:
+            cell = f"{name}.{mix}"
+            spec["workloads"].append({"name": cell, "config": name,
+                                      "traffic": mix, "chips": 1,
+                                      "why": "CPU tests"})
+            (bench / "cells" / f"{cell}.json").write_text(
+                json.dumps(TINY_LIMITS))
+            for m in spec["end_to_end"] + spec["per_layer"]:
+                if "workloads" in m:
+                    m["workloads"].append(cell)
+
+    base = json.loads((bench / "configs" / "granite-3-8b.json").read_text())
+    tiny = dict(base, **TINY_CONF)
+    add(tiny)
+    for fam, (source, keys) in (families or {}).items():
+        (bench / "families" / f"{fam}.py").write_text(source)
+        add(dict(tiny, **keys, name=f"tiny_{fam}", family=fam))
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root, bench

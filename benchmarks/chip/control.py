@@ -48,8 +48,7 @@ def main(argv=None) -> int:
         row = {"seed": seed, "program": out.notes["gaps"],
                "program_correct": out.result["correct"]}
         for m in modes:
-            g = harness.check(out.weights, cell.conf, out.sample,
-                              cell.mix["max_tokens"], mode=m)
+            g = harness.check(cell, out.weights, out.sample, mode=m)
             row[m] = g
             row[m + "_correct"] = harness.judge(g, 0, cell.limits)[0]
         row.update(sampled_tokens=sum(len(s[1]) for s in out.sample),

@@ -65,8 +65,7 @@ def test_the_control_is_not_correct(tree, seed):
                       keep_weights=True)
     assert out.result["correct"] is True
     for mode in reference.CONTROL[harness.served_dtype(cell.conf)]:
-        gaps = harness.check(out.weights, cell.conf, out.sample,
-                             cell.mix["max_tokens"], mode=mode)
+        gaps = harness.check(cell, out.weights, out.sample, mode=mode)
         assert harness.judge(gaps, 0, cell.limits)[0] is False
 
 

@@ -143,7 +143,7 @@ def phase_serve(cfg: ModelConfig, *, block_size: int, hbm_blocks: int,
             "n_kv_heads": cfg.n_kv_heads, "d_ff": cfg.d_ff,
             "vocab": cfg.vocab, "dtype": cfg.dtype, "n_params": int(n_params),
             "block_size": block_size, "pool_blocks": eng.pool.n_blocks,
-            "pool_bytes": int(eng.pool.kpool.nbytes + eng.pool.vpool.nbytes),
+            "pool_bytes": int(sum(a.nbytes for a in eng.pool.arrays)),
             "completions": len(done),
             "tokens": sum(len(c.tokens) for c in done),
             "serve_seconds": serve_s, "pool_hits": pool_stats.hits,

@@ -13,6 +13,7 @@ ARCH_IDS = [
     "chatglm3-6b", "olmo-1b", "granite-3-8b", "phi3-medium-14b",
     "llava-next-mistral-7b", "zamba2-2.7b", "whisper-tiny",
     "olmoe-1b-7b", "kimi-k2-1t-a32b", "falcon-mamba-7b",
+    "moonlight-16b-a3b",
 ]
 
 _MODULE_OF = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")
@@ -44,6 +45,9 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     if cfg.family == "moe":
         kw.update(n_experts=8, experts_per_tok=2, moe_d_ff=32,
                   n_shared_experts=min(cfg.n_shared_experts, 1))
+    if cfg.kv_lora_rank:
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                  v_head_dim=16)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=8, ssm_head_dim=16, ssm_chunk=32, dt_rank=8)
     if cfg.family == "hybrid":

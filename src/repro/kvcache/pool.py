@@ -2,7 +2,11 @@
 
 The block table of a paged KV cache is a metadata structure mapping
 logical (sequence, block-index) -> physical HBM block — exactly the
-LBN->PBN mapping of the paper (DESIGN.md §2).  The pool is two-tiered:
+LBN->PBN mapping of the paper (DESIGN.md §2).  A block holds, for
+``block_size`` tokens in every layer, what the model's ``cache_spec``
+names: K and V for GQA/MHA (``kpool``, ``vpool``), one latent row for
+MLA (``latent``).  Each named array is an attribute of the pool, and
+every copy path moves all of them.  The pool is two-tiered:
 
     HBM  (jnp arrays)  <- Clock2Q+ decides residency (ProdClock2QPlus)
     host (numpy mirror) <- eviction target ("disk"); dirty = HBM-only
@@ -47,11 +51,14 @@ class PoolStats:
 
 
 @jax.jit
-def _gather_slots(kpool, vpool, slots):
-    """The K and V blocks at ``slots``, slot-major ``(n, L, bs, H, hd)``,
-    so each block is one contiguous row on the host."""
-    return (jnp.moveaxis(kpool[:, slots], 1, 0),
-            jnp.moveaxis(vpool[:, slots], 1, 0))
+def _gather_slots(arrays, slots):
+    """Each array's blocks at ``slots``, slot-major ``(n, L, bs, ...)``,
+    so each block is one contiguous row on the host.  One dynamic slice
+    a block: a gather over the slot axis of a pool whose rows span
+    several 128-lane tiles (the MLA latent) copies whole slices of the
+    pool first."""
+    return tuple(jnp.stack([jax.lax.dynamic_index_in_dim(a, s, 1, False)
+                            for s in slots]) for a in arrays)
 
 
 class BlockPool:
@@ -69,7 +76,6 @@ class BlockPool:
         self.cfg = cfg
         self.bs = block_size
         self.n_blocks = n_hbm_blocks
-        L, H, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
         # n_shards > 1 selects the sharded concurrent policy backend
         # (repro.shardcache); the pool API is identical either way.
         # rebalance_headroom=1.0 keeps the block arrays at the stated HBM
@@ -95,10 +101,15 @@ class BlockPool:
                 max_capacity=max(n_hbm_blocks, max_hbm_blocks), **seg_kw)
         # the block arrays cover the policy's full payload-handle space
         # (>= n_hbm_blocks when resize headroom / sharding is configured)
-        self.kpool = jnp.zeros((L, self.policy.n_slots, block_size, H, hd),
-                               dtype)
-        self.vpool = jnp.zeros_like(self.kpool)
-        self.host: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        spec = cfg.cache_spec()
+        self.names = tuple(spec)
+        for name, shape in spec.items():
+            setattr(self, name, jnp.zeros(
+                (cfg.n_layers, self.policy.n_slots, block_size) + shape,
+                dtype))
+        self.block_bytes = sum(a.nbytes // a.shape[1] for a in self.arrays)
+        # a host copy of a block: one array per name, in ``names`` order
+        self.host: Dict[int, Tuple[np.ndarray, ...]] = {}
         self.n_host_blocks = n_host_blocks or 4 * n_hbm_blocks
         # pool-tier telemetry (the policy keeps its own sink; merged by
         # obs_snapshot()).  ``stats`` is a compat view over these.
@@ -112,12 +123,13 @@ class BlockPool:
         self._c_swap_in = swap_fam.labels("in")
         self._c_swap_out = swap_fam.labels("out")
         self._c_writes = self.obs.counter(
-            "pool_block_writes_total", (), "prefill block writes (two "
-            "eager whole-pool updates each)").labels()
+            "pool_block_writes_total", (), "prefill block writes (one "
+            "eager whole-pool update per pool array each)").labels()
         self._c_flushes = self.obs.counter(
             "pool_flushes_total", (), "flushes that copied a dirty block "
             "to host (batched per flusher pass on the unhardened path, "
-            "two blocking device->host copies each under faults=)").labels()
+            "one blocking device->host copy per pool array each under "
+            "faults=)").labels()
         self._c_flush_batches = self.obs.counter(
             "pool_flush_batches_total", (), "device->host transfers the "
             "flusher made (one per pass that copied a block; unhardened "
@@ -128,6 +140,9 @@ class BlockPool:
         self._g_host = self.obs.gauge(
             "pool_host_blocks", (), "blocks mirrored in the host "
             "tier").labels()
+        self.obs.gauge("pool_block_bytes", (), "device bytes of one block "
+                       "(every layer, every cache array)").labels().set(
+                           float(self.block_bytes))
         self.obs.on_collect(lambda: self._g_host.set(float(len(self.host))))
         # per-tenant lookup attribution (serving threads tenant labels
         # through admissions; None keeps the historical unlabeled keys)
@@ -193,6 +208,16 @@ class BlockPool:
             from repro.tuning import OnlineTuner
             tkw.setdefault("retune_every", max(1024, 32 * n_hbm_blocks))
             self.tuner = OnlineTuner(self.policy, obs=self.obs, **tkw)
+
+    @property
+    def arrays(self) -> Tuple[jnp.ndarray, ...]:
+        """The block arrays, in ``names`` order."""
+        return tuple(getattr(self, n) for n in self.names)
+
+    @arrays.setter
+    def arrays(self, arrays) -> None:
+        for n, a in zip(self.names, arrays):
+            setattr(self, n, a)
 
     @property
     def stats(self) -> PoolStats:
@@ -274,13 +299,16 @@ class BlockPool:
         self._c_swap_out.value += 1
 
     def _copy_out(self, key: int, slot: int) -> None:
-        self.host[key] = (np.asarray(self.kpool[:, slot]),
-                          np.asarray(self.vpool[:, slot]))
+        self.host[key] = tuple(np.asarray(a[:, slot]) for a in self.arrays)
 
     def _copy_in(self, key: int, slot: int) -> None:
-        k, v = self.host[key]
-        self.kpool = self.kpool.at[:, slot].set(jnp.asarray(k))
-        self.vpool = self.vpool.at[:, slot].set(jnp.asarray(v))
+        self._set_block(slot, [jnp.asarray(h) for h in self.host[key]])
+
+    def _set_block(self, slot: int, blocks) -> None:
+        """One eager whole-pool update per array, each array replaced
+        before the next is copied (one pool array's worth of headroom)."""
+        for n, b in zip(self.names, blocks):
+            setattr(self, n, getattr(self, n).at[:, slot].set(b))
 
     def _swap_in(self, key: int, slot: int) -> bool:
         """Host -> HBM copy through the hardened path.  False = the copy
@@ -306,11 +334,11 @@ class BlockPool:
         self._c_swap_in.value += 1
         return True
 
-    def write_block(self, slot: int, k: jnp.ndarray, v: jnp.ndarray,
+    def write_block(self, slot: int, *blocks: jnp.ndarray,
                     key: Optional[int] = None) -> None:
-        """k/v: (L, block_size, H, hd) — fill a block after prefill."""
-        self.kpool = self.kpool.at[:, slot].set(k)
-        self.vpool = self.vpool.at[:, slot].set(v)
+        """Fill a block after prefill: one (L, block_size, ...) array per
+        pool array, in ``names`` order (k and v: (L, block_size, H, hd))."""
+        self._set_block(slot, blocks)
         self._c_writes.value += 1
         if key is not None:
             self.policy.io_done(key)
@@ -378,13 +406,12 @@ class BlockPool:
             # every pass of up to max_blocks copies
             idx = np.full(max_blocks, slots[-1], np.int32)
             idx[:len(slots)] = slots
-            kb, vb = jax.device_get(_gather_slots(self.kpool, self.vpool,
-                                                  idx))
+            got = jax.device_get(_gather_slots(self.arrays, idx))
             if len(copy) < max_blocks:
                 # the host rows are views: keep no padded row alive
-                kb, vb = kb[:len(copy)].copy(), vb[:len(copy)].copy()
+                got = [g[:len(copy)].copy() for g in got]
             for i, key in enumerate(copy):
-                self.host[key] = (kb[i], vb[i])
+                self.host[key] = tuple(g[i] for g in got)
             self._c_flush_batches.value += 1
             self._c_swap_out.value += len(copy)
             self._c_flushes.value += len(copy)

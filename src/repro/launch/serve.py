@@ -48,8 +48,10 @@ def fit_pool_blocks(cfg: ModelConfig, block_size: int) -> int:
         raise ValueError(f"{device.platform} device reports no memory "
                          "limit; pass --hbm-blocks explicitly")
     free = stats["bytes_limit"] - stats["bytes_in_use"] - POOL_RESERVE_BYTES
-    # one block: K and V of block_size tokens in every layer
-    block_bytes = (2 * cfg.n_layers * block_size * cfg.n_kv_heads * cfg.hd
+    # one block: what the cache spec holds of block_size tokens in every
+    # layer (K and V, or the latent row)
+    per_token = sum(int(np.prod(s)) for s in cfg.cache_spec().values())
+    block_bytes = (cfg.n_layers * block_size * per_token
                    * L.dtype_of(cfg).itemsize)
     n = free // (2 * block_bytes)
     if n < 1:

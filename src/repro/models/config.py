@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -24,13 +24,24 @@ class ModelConfig:
     act: str = "swiglu"         # swiglu | gelu
     qkv_bias: bool = False
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6      # RMSNorm epsilon
+    # -- latent attention (MLA, deepseek_v3; kv_lora_rank 0: none) -------------
+    kv_lora_rank: int = 0       # the latent c cached per token per layer
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0   # k_pe: one per token, shared by the heads
+    v_head_dim: int = 0
     # -- MoE --------------------------------------------------------------------
     n_experts: int = 0
     experts_per_tok: int = 0
     moe_d_ff: int = 0
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # 0: dropless
     router_aux_coef: float = 0.01
+    router: str = "softmax"     # softmax | sigmoid (noaux_tc: a bias chooses)
+    routed_scaling: float = 1.0  # routed gates' factor (sigmoid router)
+    n_dense_layers: int = 0     # leading layers with a dense MLP (d_ff)
+    experts_held: int = 0       # experts this chip holds (0: all n_experts)
+    expert_first: int = 0       # index of the first held expert
     # -- SSM (mamba1/mamba2) ------------------------------------------------------
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -54,6 +65,23 @@ class ModelConfig:
         return self.head_dim or (self.d_model // self.n_heads)
 
     @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def cache_spec(self) -> Dict[str, Tuple[int, ...]]:
+        """The paged pool's arrays, each by name with what it holds of
+        one token in one layer: K and V heads for GQA/MHA, the latent
+        row ``[c | k_pe]`` for MLA.  The latent row is padded to whole
+        128-lane tiles (576 -> 640): with a minor dimension of 576 the
+        TPU lays the pool out slot-minor, and every block gather or row
+        write then relayouts whole layers of the pool."""
+        if self.kv_lora_rank:
+            width = self.kv_lora_rank + self.qk_rope_head_dim
+            return {"latent": (-(-width // 128) * 128,)}
+        return {"kpool": (self.n_kv_heads, self.hd),
+                "vpool": (self.n_kv_heads, self.hd)}
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -75,16 +103,24 @@ class ModelConfig:
         D, V, hd = self.d_model, self.vocab, self.hd
         emb = V * D * (1 if self.tie_embeddings else 2)
         per_attn = D * (self.n_heads * hd) * 2 + D * (self.n_kv_heads * hd) * 2
+        if self.kv_lora_rank:
+            H, R, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            per_attn = (D * H * (self.qk_nope_head_dim + rope) + D * (R + rope)
+                        + R * H * (self.qk_nope_head_dim + self.v_head_dim)
+                        + H * self.v_head_dim * D)
         n = emb
         if self.family in ("dense", "vlm", "moe"):
             mlp_mult = 3 if self.act == "swiglu" else 2
+            dense_mlp = mlp_mult * D * self.d_ff
             if self.family == "moe":
-                per_mlp = self.n_experts * mlp_mult * D * self.moe_d_ff \
+                per_mlp = self.n_held * mlp_mult * D * self.moe_d_ff \
                     + D * self.n_experts \
                     + self.n_shared_experts * mlp_mult * D * self.moe_d_ff
             else:
-                per_mlp = mlp_mult * D * self.d_ff
-            n += self.n_layers * (per_attn + per_mlp)
+                per_mlp = dense_mlp
+            k = self.n_dense_layers
+            n += self.n_layers * per_attn + k * dense_mlp \
+                + (self.n_layers - k) * per_mlp
         elif self.family == "ssm":
             din, N, R = self.d_inner, self.ssm_state, self.dt_rank_
             per = (D * 2 * din + din * self.ssm_conv + din * (R + 2 * N)
@@ -111,10 +147,11 @@ class ModelConfig:
             return self.n_params()
         D = self.d_model
         mlp_mult = 3 if self.act == "swiglu" else 2
-        dense_side = self.n_params() - self.n_layers * (
-            self.n_experts * mlp_mult * D * self.moe_d_ff)
-        active_moe = self.n_layers * (self.experts_per_tok * mlp_mult * D
-                                      * self.moe_d_ff)
+        n_moe = self.n_layers - self.n_dense_layers
+        dense_side = self.n_params() - n_moe * (
+            self.n_held * mlp_mult * D * self.moe_d_ff)
+        active_moe = n_moe * (self.experts_per_tok * mlp_mult * D
+                              * self.moe_d_ff)
         return dense_side + active_moe
 
 

@@ -58,7 +58,7 @@ def nonparam_ln(x, eps: float = 1e-5):
 
 def make_norm(cfg: ModelConfig):
     if cfg.norm == "rmsnorm":
-        return lambda x, p: rmsnorm(x, p["w"])
+        return lambda x, p: rmsnorm(x, p["w"], cfg.norm_eps)
     if cfg.norm == "layernorm":
         return lambda x, p: layernorm(x, p["w"], p["b"])
     if cfg.norm == "nonparam_ln":

@@ -7,7 +7,13 @@ Three entry points per model family:
 
 KV cache layout: k/v as (L, B, S_max, H_kv, hd); sharded (None, "data",
 "model", None, None) at scale so a 32k/500k cache divides across the pod
-without replicating GQA heads (DESIGN.md §6).
+without replicating GQA heads (DESIGN.md §6).  A model with latent
+attention (``kv_lora_rank``, ``models/mla.py``) caches one latent row a
+token a layer instead, and decodes only through the paged pool.
+
+A model with leading dense layers (``n_dense_layers``) keeps attention
+and norms in one stack over every layer (``blocks``) and its MLPs in two,
+``dense`` and ``moe``; the layer scan picks each layer's from its index.
 """
 
 from __future__ import annotations
@@ -18,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as moe_lib
 from repro.models.config import ModelConfig
 
@@ -28,8 +35,22 @@ class KVCache(NamedTuple):
     length: jnp.ndarray     # scalar int32: #valid positions
 
 
+class LatentCache(NamedTuple):
+    latent: jnp.ndarray     # (L, B, S_max, cache_spec()["latent"] width)
+    length: jnp.ndarray
+
+
+def _contiguous_kv(cfg: ModelConfig) -> None:
+    if cfg.kv_lora_rank or cfg.n_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention and split MLP stacks decode "
+            "through the paged pool (forward_decode_paged), not a "
+            "contiguous K/V cache")
+
+
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                   n_layers: Optional[int] = None) -> KVCache:
+    _contiguous_kv(cfg)
     nl = cfg.n_layers if n_layers is None else n_layers
     shape = (nl, batch, max_len, cfg.n_kv_heads, cfg.hd)
     z = jnp.zeros(shape, L.dtype_of(cfg))
@@ -41,8 +62,11 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
 def block_params(cfg: ModelConfig, rng) -> Dict:
     ks = jax.random.split(rng, 4)
     p = {"ln1": L.norm_params(cfg, ks[0]),
-         "attn": L.attn_params(cfg, ks[1]),
+         "attn": mla.mla_params(cfg, ks[1]) if cfg.kv_lora_rank
+         else L.attn_params(cfg, ks[1]),
          "ln2": L.norm_params(cfg, ks[2])}
+    if cfg.n_dense_layers:
+        return p            # the MLPs live in the dense and moe stacks
     if cfg.family == "moe":
         p["moe"] = moe_lib.moe_params(cfg, ks[3])
     else:
@@ -50,38 +74,75 @@ def block_params(cfg: ModelConfig, rng) -> Dict:
     return p
 
 
+def _stacked(make, rng, n: int) -> Dict:
+    """``n`` draws of ``make`` stacked on a leading layer axis.
+    ``lax.map`` (not ``vmap``) draws one layer at a time, so the float32
+    draws that ``dense_init`` casts down never exist for all layers at
+    once."""
+    return jax.lax.map(make, jax.random.split(rng, n))
+
+
 def stacked_block_params(cfg: ModelConfig, rng) -> Dict:
-    """Per-layer params stacked on a leading layer axis.  ``lax.map``
-    (not ``vmap``) draws one layer at a time, so the float32 draws that
-    ``dense_init`` casts down never exist for all layers at once."""
-    rngs = jax.random.split(rng, cfg.n_layers)
-    return jax.lax.map(lambda r: block_params(cfg, r), rngs)
+    """Per-layer params stacked on a leading layer axis."""
+    return _stacked(lambda r: block_params(cfg, r), rng, cfg.n_layers)
 
 
 # -- block application -------------------------------------------------------------
 
-def _mix(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
-         decode: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The channel-mixing half (MLP or MoE). Returns (out, aux_loss)."""
-    if cfg.family == "moe":
-        return moe_lib.moe_apply(cfg, p["moe"], x, decode=decode)
-    return L.mlp_apply(cfg, p["mlp"], x), jnp.float32(0.0)
+def _scan_xs(cfg: ModelConfig, params: Dict):
+    """What the layer scan walks: each layer's block, and, where the
+    MLPs are in stacks of their own, the layer's index."""
+    idx = jnp.arange(cfg.n_layers) if cfg.n_dense_layers else None
+    return params["blocks"], idx
 
 
-def block_full(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
-               positions: jnp.ndarray, causal: bool = True):
-    """Full-sequence block. Returns (x, (k, v), aux)."""
+def _mix_at(cfg: ModelConfig, params: Dict, p: Dict, i, h: jnp.ndarray,
+            decode: bool, valid=None):
+    """Layer ``i``'s channel mixing (MLP or MoE): (out, aux_loss, expert
+    counts, None for a dense MLP; see ``moe.moe_apply``).  ``params`` and
+    ``i`` are read only where the MLPs are in stacks of their own."""
+    if not cfg.n_dense_layers:
+        if cfg.family == "moe":
+            return moe_lib.moe_apply(cfg, p["moe"], h, decode, valid)
+        return L.mlp_apply(cfg, p["mlp"], h), jnp.float32(0.0), None
+    k = cfg.n_dense_layers
+
+    def at(tree, j):
+        return jax.tree.map(lambda a: a[j], tree)
+
+    def dense(h):
+        m = L.mlp_apply(cfg, at(params["dense"], jnp.minimum(i, k - 1)), h)
+        return m, jnp.float32(0.0), jnp.zeros((cfg.n_held + 1,), jnp.int32)
+
+    def expert(h):
+        return moe_lib.moe_apply(cfg, at(params["moe"], jnp.maximum(i - k, 0)),
+                                 h, decode, valid)
+
+    return jax.lax.cond(i < k, dense, expert, h)
+
+
+def block_full(cfg: ModelConfig, params: Dict, xs, x: jnp.ndarray,
+               positions: jnp.ndarray, causal: bool = True, valid=None):
+    """Full-sequence block; ``xs`` is one step of ``_scan_xs``.  Returns
+    (x, cache arrays in ``cache_spec`` order, aux, expert counts)."""
+    p, i = xs
     norm = L.make_norm(cfg)
     h = norm(x, p["ln1"])
-    q, k, v = L.qkv_proj(cfg, p["attn"], h)
-    q = L.apply_rope(q, positions, cfg.rope_theta, cfg.rope_frac)
-    k = L.apply_rope(k, positions, cfg.rope_theta, cfg.rope_frac)
-    o = L.attention(q, k, v, causal=causal)
-    o = jnp.einsum("bqx,xd->bqd", o.reshape(*o.shape[:2], -1), p["attn"]["wo"])
+    if cfg.kv_lora_rank:
+        o, latent = mla.attend_full(cfg, p["attn"], h, positions, causal)
+        cache = (latent,)
+    else:
+        q, k, v = L.qkv_proj(cfg, p["attn"], h)
+        q = L.apply_rope(q, positions, cfg.rope_theta, cfg.rope_frac)
+        k = L.apply_rope(k, positions, cfg.rope_theta, cfg.rope_frac)
+        o = L.attention(q, k, v, causal=causal)
+        o = jnp.einsum("bqx,xd->bqd", o.reshape(*o.shape[:2], -1),
+                       p["attn"]["wo"])
+        cache = (k, v)
     x = x + o
     h = norm(x, p["ln2"])
-    m, aux = _mix(cfg, p, h)
-    return x + m, (k, v), aux
+    m, aux, counts = _mix_at(cfg, params, p, i, h, False, valid)
+    return x + m, cache, aux, counts
 
 
 def block_decode(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
@@ -100,7 +161,7 @@ def block_decode(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     o = jnp.einsum("bqx,xd->bqd", o.reshape(B, 1, -1), p["attn"]["wo"])
     x = x + o
     h = norm(x, p["ln2"])
-    m, _ = _mix(cfg, p, h, decode=True)
+    m = _mix_at(cfg, None, p, None, h, True)[0]
     return x + m, kc, vc
 
 
@@ -145,8 +206,15 @@ def embed_inputs(cfg: ModelConfig, p: Dict, batch: Dict) -> jnp.ndarray:
 
 def init_params(cfg: ModelConfig, rng) -> Dict:
     k1, k2 = jax.random.split(rng)
-    return {"embed": embed_params(cfg, k1),
-            "blocks": stacked_block_params(cfg, k2)}
+    p = {"embed": embed_params(cfg, k1),
+         "blocks": stacked_block_params(cfg, k2)}
+    if cfg.n_dense_layers:
+        kd, km = jax.random.split(jax.random.fold_in(k2, 1))
+        p["dense"] = _stacked(lambda r: L.mlp_params(cfg, r), kd,
+                              cfg.n_dense_layers)
+        p["moe"] = _stacked(lambda r: moe_lib.moe_params(cfg, r), km,
+                            cfg.n_layers - cfg.n_dense_layers)
+    return p
 
 
 # -- forward passes ----------------------------------------------------------------------
@@ -158,82 +226,122 @@ def forward_train(cfg: ModelConfig, params: Dict, batch: Dict,
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    def body(carry, p):
+    def body(carry, xs):
         x, aux = carry
-        x, _, a = block_full(cfg, p, x, positions)
+        x, _, a, _ = block_full(cfg, params, xs, x, positions)
         return (x, aux + a), None
 
     if remat:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), params["blocks"])
+    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
+                               _scan_xs(cfg, params))
     return lm_logits(cfg, params["embed"], x), aux
 
 
-def forward_prefill(cfg: ModelConfig, params: Dict, batch: Dict,
-                    max_len: Optional[int] = None,
-                    full_logits: bool = False):
-    """Returns (logits, KVCache); logits cover the last position only
-    unless ``full_logits`` (used by the serving engine's length-bucketed
-    prefill, where the "last real token" is not the last position)."""
+def _prefill(cfg: ModelConfig, params: Dict, batch: Dict,
+             max_len: Optional[int], full_logits: bool, valid=None):
+    """(logits, cache arrays (L, B, max_len, ...) in ``cache_spec``
+    order, expert counts (L, n_held + 1) or None, the positions
+    computed)."""
     x = embed_inputs(cfg, params["embed"], batch)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     max_len = max_len or S
 
-    def body(x, p):
-        x, (k, v), _ = block_full(cfg, p, x, positions)
+    def body(x, xs):
+        x, cache, _, counts = block_full(cfg, params, xs, x, positions,
+                                         valid=valid)
         if max_len > S:
-            pad = [(0, 0), (0, max_len - S), (0, 0), (0, 0)]
-            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        return x, (k, v)
+            cache = tuple(jnp.pad(a, [(0, 0), (0, max_len - S)]
+                                  + [(0, 0)] * (a.ndim - 2)) for a in cache)
+        return x, (cache, counts)
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    x, (caches, counts) = jax.lax.scan(body, x, _scan_xs(cfg, params))
     logits = lm_logits(cfg, params["embed"],
                        x if full_logits else x[:, -1:, :])
-    return logits, KVCache(ks, vs, jnp.int32(S))
+    return logits, caches, counts, S
+
+
+def forward_prefill(cfg: ModelConfig, params: Dict, batch: Dict,
+                    max_len: Optional[int] = None,
+                    full_logits: bool = False):
+    """Returns (logits, KVCache, or LatentCache with latent attention);
+    logits cover the last position only unless ``full_logits``."""
+    logits, caches, _, S = _prefill(cfg, params, batch, max_len, full_logits)
+    make = LatentCache if cfg.kv_lora_rank else KVCache
+    return logits, make(*caches, jnp.int32(S))
+
+
+def prefill_paged(cfg: ModelConfig, params: Dict, tokens: jnp.ndarray,
+                  n_valid=None):
+    """The serving engine's prefill of ``tokens`` (1, S), padded past
+    its real tokens to a length bucket.  Returns (logits (1, S, V), the
+    pool arrays' contents (L, 1, S, ...) in ``cache_spec`` order, and
+    for an expert model given ``n_valid`` the token-expert counts
+    (L, n_held + 1) of the first ``n_valid`` tokens, else None)."""
+    counting = cfg.family == "moe" and n_valid is not None
+    valid = jnp.arange(tokens.shape[1])[None] < n_valid if counting else None
+    logits, caches, counts, _ = _prefill(cfg, params, {"tokens": tokens},
+                                         None, True, valid)
+    return logits, caches, counts if counting else None
 
 
 def forward_decode_paged(cfg: ModelConfig, params: Dict, tokens: jnp.ndarray,
-                         kpool: jnp.ndarray, vpool: jnp.ndarray,
+                         pools: Tuple[jnp.ndarray, ...],
                          block_tables: jnp.ndarray, lengths: jnp.ndarray,
-                         slot_ids: jnp.ndarray, slot_offs: jnp.ndarray):
-    """Paged decode: gather K/V through block tables (vLLM-style).
+                         slot_ids: jnp.ndarray, slot_offs: jnp.ndarray,
+                         n_valid=None):
+    """Paged decode: gather the cache through block tables (vLLM-style).
 
-    kpool/vpool: (L, N, bs, H_kv, hd); block_tables: (B, nb);
+    pools: the pool's arrays in ``cache_spec`` order, (L, N, bs, ...)
+    each: K and V, or the latent rows; block_tables: (B, nb);
     lengths: (B,) current context length; slot_ids/slot_offs: (B,) where
-    this step's k/v are written in the pool.  Returns (logits, kpool,
-    vpool).  The jnp gather here is the reference semantics of the
+    this step's cache entries are written in the pool.  Returns (logits,
+    pools, and for an expert model given ``n_valid`` the token-expert
+    counts (L, n_held + 1) of the first ``n_valid`` rows, else None).
+    The jnp gather here is the reference semantics of the
     kernels/paged_attention Pallas kernel.
     """
     x = embed_tokens(cfg, params["embed"], tokens)
     B = tokens.shape[0]
-    bs = kpool.shape[2]
     norm = L.make_norm(cfg)
     posb = lengths[:, None].astype(jnp.int32)  # (B,1) rope positions
+    counting = cfg.family == "moe" and n_valid is not None
+    valid = (jnp.arange(B) < n_valid)[:, None] if counting else None
 
     def body(x, inp):
-        p, kp, vp = inp
+        xs, pool = inp
+        p = xs[0]
         h = norm(x, p["ln1"])
-        q, k, v = L.qkv_proj(cfg, p["attn"], h)
-        q = L.apply_rope(q, posb, cfg.rope_theta, cfg.rope_frac)
-        k = L.apply_rope(k, posb, cfg.rope_theta, cfg.rope_frac)
-        # write this token's k/v into its pool slot
-        kp = kp.at[slot_ids, slot_offs].set(k[:, 0])
-        vp = vp.at[slot_ids, slot_offs].set(v[:, 0])
-        # gather the sequence's blocks: (B, nb, bs, H, hd) -> (B, S', H, hd)
-        kc = kp[block_tables].reshape(B, -1, kp.shape[-2], kp.shape[-1])
-        vc = vp[block_tables].reshape(B, -1, vp.shape[-2], vp.shape[-1])
-        o = L.attention(q, kc, vc, causal=False, kv_len=lengths + 1)
-        o = jnp.einsum("bqx,xd->bqd", o.reshape(B, 1, -1), p["attn"]["wo"])
+        if cfg.kv_lora_rank:
+            o, lp = mla.attend_paged(cfg, p["attn"], h, posb, pool[0],
+                                     block_tables, lengths, slot_ids,
+                                     slot_offs)
+            pool = (lp,)
+        else:
+            kp, vp = pool
+            q, k, v = L.qkv_proj(cfg, p["attn"], h)
+            q = L.apply_rope(q, posb, cfg.rope_theta, cfg.rope_frac)
+            k = L.apply_rope(k, posb, cfg.rope_theta, cfg.rope_frac)
+            # write this token's k/v into its pool slot
+            kp = kp.at[slot_ids, slot_offs].set(k[:, 0])
+            vp = vp.at[slot_ids, slot_offs].set(v[:, 0])
+            # gather the sequence's blocks: (B, nb, bs, H, hd) -> (B, S', H, hd)
+            kc = kp[block_tables].reshape(B, -1, kp.shape[-2], kp.shape[-1])
+            vc = vp[block_tables].reshape(B, -1, vp.shape[-2], vp.shape[-1])
+            o = L.attention(q, kc, vc, causal=False, kv_len=lengths + 1)
+            o = jnp.einsum("bqx,xd->bqd", o.reshape(B, 1, -1), p["attn"]["wo"])
+            pool = (kp, vp)
         x = x + o
         h = norm(x, p["ln2"])
-        m, _ = _mix(cfg, p, h, decode=True)
-        return x + m, (kp, vp)
+        m, _, counts = _mix_at(cfg, params, p, xs[1], h, True, valid)
+        return x + m, (pool, counts)
 
-    x, (kpool, vpool) = jax.lax.scan(body, x, (params["blocks"], kpool, vpool))
+    x, (pools, counts) = jax.lax.scan(
+        body, x, (_scan_xs(cfg, params), tuple(pools)))
     logits = lm_logits(cfg, params["embed"], x)
-    return logits, kpool, vpool
+    return logits, pools, counts if counting else None
 
 
 class BufferedKVCache(NamedTuple):
@@ -255,6 +363,7 @@ class BufferedKVCache(NamedTuple):
 
 def init_buffered_cache(cfg: ModelConfig, batch: int, max_len: int,
                         buf_len: int = 256) -> BufferedKVCache:
+    _contiguous_kv(cfg)
     dt = L.dtype_of(cfg)
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.hd)
     bshape = (cfg.n_layers, batch, buf_len, cfg.n_kv_heads, cfg.hd)
@@ -286,7 +395,7 @@ def forward_decode_buffered(cfg: ModelConfig, params: Dict,
         o = jnp.einsum("bqx,xd->bqd", o.reshape(B, 1, -1), p["attn"]["wo"])
         x = x + o
         h = norm(x, p["ln2"])
-        m, _ = _mix(cfg, p, h, decode=True)
+        m = _mix_at(cfg, None, p, None, h, True)[0]
         return x + m, (bk, bv)
 
     x, (bks, bvs) = jax.lax.scan(
@@ -313,6 +422,7 @@ def commit_buffer(cfg: ModelConfig, cache: BufferedKVCache) -> BufferedKVCache:
 def forward_decode(cfg: ModelConfig, params: Dict, tokens: jnp.ndarray,
                    cache: KVCache):
     """tokens: (B, 1). Returns (logits (B,1,V), updated cache)."""
+    _contiguous_kv(cfg)
     x = embed_tokens(cfg, params["embed"], tokens)
     pos = cache.length
 

@@ -114,7 +114,7 @@ class AdmissionConfig:
                           considered met (0 = exact).
     """
 
-    queue_bound: int = 64
+    queue_bound: int = 128
     n_classes: int = 3
     tenant_weights: Optional[Dict[str, float]] = None
     age_ticks: int = 64

@@ -124,49 +124,92 @@ class ServingEngine:
         self._g_pending = depth_fam.labels("pending")
         self._g_active = depth_fam.labels("active")
         self._admit_ts: Dict[int, float] = {}
+        # an expert model's programs also count the token-expert pairs of
+        # the real rows (moe.moe_apply), fetched with the logits; others
+        # take no row count and return no counts
+        self._experts = api.cfg.family == "moe"
+        self._step_counts = None
+        if self._experts:
+            self._init_expert_counters()
 
         # named, so the device trace shows jit_decode_step/jit_prefill_step
-        def decode_step(params, toks, kp, vp, bt, lens, sid, soff):
-            return T.forward_decode_paged(api.cfg, params, toks, kp, vp, bt,
-                                          lens, sid, soff)
+        def decode_step(params, toks, pools, bt, lens, sid, soff, *n_valid):
+            return T.forward_decode_paged(api.cfg, params, toks, pools, bt,
+                                          lens, sid, soff, *n_valid)
 
         # prompts are padded to block_size buckets so prefill compiles
         # once per bucket, not once per prompt length
-        def prefill_step(params, batch):
-            return T.forward_prefill(api.cfg, params, batch,
-                                     full_logits=True)
+        def prefill_step(params, toks, *n_valid):
+            return T.prefill_paged(api.cfg, params, toks, *n_valid)
 
         self._decode_fn = jax.jit(decode_step)
         self._prefill_fn = jax.jit(prefill_step)
 
+    def _init_expert_counters(self) -> None:
+        cfg = self.cfg
+        routed = self.obs.counter(
+            "serve_moe_routed_total", ("where",), "token-expert pairs of "
+            "served tokens routed to experts held here or elsewhere")
+        self._c_held = routed.labels("held")
+        self._c_away = routed.labels("away")
+        per = self.obs.counter(
+            "serve_moe_expert_tokens_total", ("expert",), "token-expert "
+            "pairs routed to each held expert, summed over layers")
+        self._c_expert = [per.labels(str(cfg.expert_first + e))
+                          for e in range(cfg.n_held)]
+        self._g_expert_max = self.obs.gauge(
+            "serve_moe_expert_tokens_max", (), "the most tokens one held "
+            "expert took in one layer of the last decode step").labels()
+
+    def _count_experts(self, counts: np.ndarray, decode: bool) -> None:
+        """Add a program's (layers, n_held + 1) token-expert counts."""
+        per = counts.sum(axis=0)
+        for c, n in zip(self._c_expert, per[:-1]):
+            c.value += int(n)
+        self._c_held.value += int(per[:-1].sum())
+        self._c_away.value += int(per[-1])
+        if decode:
+            self._g_expert_max.set(float(counts[:, :-1].max()))
+
+    def _fetch(self, x, counts, decode: bool) -> np.ndarray:
+        """``x`` on the host, with the step's expert counts where the
+        program made them (one transfer)."""
+        if counts is None:
+            return np.asarray(x)
+        x, counts = jax.device_get((x, counts))
+        self._count_experts(counts, decode)
+        return x
+
     # -- prefill ------------------------------------------------------------------
     def _prefill_into_pool(self, st, fill_blocks: List[int]) -> int:
-        """Run the dense prefill, write the missing blocks' KV, and return
-        the first generated token (greedy).  NOTE: prefix-cache hits avoid
-        block WRITES and deduplicate HBM (two sequences share physical
-        blocks); logits still require the full forward here — suffix-only
-        chunked prefill is future work."""
+        """Run the dense prefill, write the missing blocks' cache entries,
+        and return the first generated token (greedy).  NOTE: prefix-cache
+        hits avoid block WRITES and deduplicate HBM (two sequences share
+        physical blocks); logits still require the full forward here —
+        suffix-only chunked prefill is future work."""
         n_real = len(st.tokens)
         pad = (-n_real) % self.pool.bs  # length bucketing (one compile
         toks = list(st.tokens) + [0] * pad  # per bucket, not per length)
         with span("repro.model.prefill"):
             toks = jnp.asarray(toks, jnp.int32)[None]
-            logits, cache = self._prefill_fn(self.params, {"tokens": toks})
+            n_valid = (jnp.int32(n_real),) if self._experts else ()
+            logits, caches, counts = self._prefill_fn(self.params, toks,
+                                                      *n_valid)
         bs = self.pool.bs
         with span("repro.kv.write_blocks"):
-            k = cache.k[:, 0]  # (L, S, H, hd)
-            v = cache.v[:, 0]
+            arrays = [a[:, 0] for a in caches]  # (L, S, ...) each
             for b in fill_blocks:
                 lo, hi = b * bs, min((b + 1) * bs, len(st.tokens))
-                kb = jnp.zeros((self.cfg.n_layers, bs, self.cfg.n_kv_heads,
-                                self.cfg.hd), k.dtype)
-                kb = kb.at[:, :hi - lo].set(k[:, lo:hi])
-                vb = jnp.zeros_like(kb)
-                vb = vb.at[:, :hi - lo].set(v[:, lo:hi])
-                self.pool.write_block(st.slots[b], kb, vb,
+                blocks = []
+                for a in arrays:
+                    blk = jnp.zeros((self.cfg.n_layers, bs) + a.shape[2:],
+                                    a.dtype)
+                    blocks.append(blk.at[:, :hi - lo].set(a[:, lo:hi]))
+                self.pool.write_block(st.slots[b], *blocks,
                                       key=st.block_keys[b])
         with span("repro.engine.sample"):
-            return int(jnp.argmax(logits[0, n_real - 1]))
+            return int(self._fetch(jnp.argmax(logits[0, n_real - 1]), counts,
+                                   decode=False))
 
     # -- execution primitives (what the scheduler drives) ------------------------
     def _max_seq_blocks(self) -> int:
@@ -215,13 +258,16 @@ class ServingEngine:
                 soffs.append(soffs[-1])
                 bts.append(bts[-1])
             args = (jnp.asarray(toks, jnp.int32)[:, None],
-                    self.pool.kpool, self.pool.vpool,
+                    self.pool.arrays,
                     jnp.asarray(np.stack(bts)), jnp.asarray(poss, jnp.int32),
                     jnp.asarray(sids, jnp.int32),
                     jnp.asarray(soffs, jnp.int32))
+            if self._experts:
+                args += (jnp.int32(len(ids)),)
         with span("repro.model.decode"):
-            logits, kp, vp = self._decode_fn(self.params, *args)
-        self.pool.kpool, self.pool.vpool = kp, vp
+            logits, pools, self._step_counts = self._decode_fn(self.params,
+                                                               *args)
+        self.pool.arrays = pools
         return logits[:len(ids), 0]
 
     def _decode_step(self, ids: List[int]) -> Dict[int, int]:
@@ -231,7 +277,8 @@ class ServingEngine:
             t_step = time.perf_counter()
             logits = self._decode_logits(ids)
             with span("repro.engine.sample"):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                nxt = self._fetch(jnp.argmax(logits, axis=-1),
+                                  self._step_counts, decode=True)
             self._h_decode.observe(time.perf_counter() - t_step)
             out = {}
             for i, rid in enumerate(ids):
@@ -357,6 +404,10 @@ class EngineExecutor:
         self.eng = eng
         self.block_size = eng.pool.bs
         self.n_blocks = eng._max_seq_blocks()
+
+    @property
+    def pool_blocks(self) -> int:
+        return self.eng.pool.n_blocks
 
     @property
     def degraded(self) -> bool:

@@ -23,6 +23,8 @@ surface below — so the same decision code runs the real JAX engine
 ``SimExecutor`` the tests and SLO benchmark use:
 
     n_blocks, block_size      # capacity surface (oversize rejection)
+    pool_blocks               # blocks the pool holds (optional; the
+                              # watermark's unit, n_blocks if absent)
     free_fraction() -> float  # evictable-block fraction (backpressure)
     degraded -> bool          # faults breaker open (read-through mode)
     prefill(req) -> int       # admit + prefill; returns the first token
@@ -182,6 +184,12 @@ class Scheduler:
         self._terminal(r, ST_REJECTED, reason)
         return False
 
+    @staticmethod
+    def _blocks(r: SchedRequest, bs: int) -> int:
+        """Blocks ``r`` pins once its answer is complete (0 where the
+        executor has no blocks)."""
+        return -(-(r.prompt_len + r.max_new) // bs) if bs else 0
+
     # -- one scheduling round -------------------------------------------------
     def tick(self) -> int:
         """One virtual tick: shed expired SLOs, apply backpressure, form
@@ -212,9 +220,13 @@ class Scheduler:
         #    from the queue while budget, decode slots, and blocks last
         budget = self.cfg.token_budget - len(self.active)
         decode_ids = sorted(self.active)
-        n_blocks = max(1, getattr(self.x, "n_blocks", 1))
+        # the watermark counts the pool's blocks, as free_fraction()
+        # does, and live sequences keep the blocks they have yet to pin
+        pool = max(1, getattr(self.x, "pool_blocks", None)
+                   or getattr(self.x, "n_blocks", 1))
         bs = getattr(self.x, "block_size", 0)
-        free_est = free
+        free_est = min(free, 1.0 - sum(
+            self._blocks(r, bs) for r in self.active.values()) / pool)
         prefills: List[SchedRequest] = []
         while len(self.active) + len(prefills) < self.cfg.max_batch:
             r = self.queue.peek_best(now, max_class=max_class)
@@ -222,8 +234,7 @@ class Scheduler:
                 break
             if r.prompt_len > budget and (prefills or decode_ids):
                 break  # interleave: leftover prefill work waits a tick
-            need = -(-(r.prompt_len + r.max_new) // bs) / n_blocks \
-                if bs else 0.0
+            need = self._blocks(r, bs) / pool
             if free_est - need < adm.low_watermark and \
                     (prefills or decode_ids):
                 break  # block watermark: don't over-pin the pool

@@ -76,7 +76,8 @@ def test_vlm_patch_positions_are_masked():
 def test_param_counts_match_analytic():
     """init() leaf totals must agree with the analytic n_params() used for
     MODEL_FLOPS in the roofline."""
-    for arch in ("granite-3-8b", "olmoe-1b-7b", "falcon-mamba-7b"):
+    for arch in ("granite-3-8b", "olmoe-1b-7b", "falcon-mamba-7b",
+                 "moonlight-16b-a3b"):
         cfg = reduced(get_config(arch))
         api = build(cfg)
         shapes = jax.eval_shape(api.init, jax.random.PRNGKey(0))

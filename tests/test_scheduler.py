@@ -266,6 +266,65 @@ def test_block_watermark_never_overcommits():
     assert peak <= x.n_blocks
 
 
+class _PoolExecutor(SimExecutor):
+    """The engine's surface: ``n_blocks`` is the per-sequence cap, the
+    pool holds ``pool_blocks``, and a sequence pins its blocks as its
+    tokens arrive (prompt at prefill, one more each block boundary)."""
+
+    def __init__(self, pool_blocks, per_seq, block_size=16):
+        super().__init__(n_blocks=per_seq, block_size=block_size)
+        self.pool_blocks = pool_blocks
+        self._len = {}
+        self.peak = 0
+
+    def _pin(self):
+        self.used = sum(-(-n // self.block_size) for n in self._len.values())
+        self.peak = max(self.peak, self.used)
+
+    def free_fraction(self):
+        return 1.0 - self.used / self.pool_blocks
+
+    def prefill(self, r):
+        tok = super().prefill(r)
+        self._len[r.req_id] = r.prompt_len
+        self._pin()
+        return tok
+
+    def decode(self, ids):
+        for rid in ids:
+            self._len[rid] += 1
+        self._pin()
+        return super().decode(ids)
+
+    def release(self, req_id):
+        super().release(req_id)
+        self._len.pop(req_id)
+        self._pin()
+
+
+def test_block_watermark_counts_the_pools_blocks():
+    # each request may pin most of the per-sequence cap, but the pool
+    # holds many sequences: the batch fills in the first tick
+    x = _PoolExecutor(pool_blocks=4096, per_seq=8)
+    s = Scheduler(x, config=SchedConfig(token_budget=1 << 20, max_batch=8))
+    for i in range(8):
+        assert s.submit(SchedRequest(req_id=i, prompt_len=100, max_new=20))
+    s.tick()
+    assert len(s.active) == 8
+
+
+def test_block_watermark_reserves_what_live_sequences_will_pin():
+    # pins grow as answers decode: admission keeps room for the blocks
+    # live sequences have yet to pin, so a full pool is never over-pinned
+    x = _PoolExecutor(pool_blocks=8, per_seq=8)
+    reqs = [SchedRequest(req_id=i, prompt_len=16, max_new=60, priority=0)
+            for i in range(6)]
+    s, outs = _run(reqs, [0] * 6, x=x,
+                   cfg=SchedConfig(token_budget=1 << 20, max_batch=4))
+    assert all(o.status == ST_COMPLETED for o in outs)
+    assert x.peak <= x.pool_blocks
+
+
 # =============================================================================
 # degraded-mode backpressure (sim chaos)
 # =============================================================================

@@ -98,12 +98,58 @@ def test_served_decode_step_compiles(one_chip, no_compile_cache):
                   jnp.bfloat16)
     vec = _shape(one_chip, (BATCH,), jnp.int32)
     step = jax.jit(lambda p, tk, kp, vp, bt, ln, si, so:
-                   T.forward_decode_paged(cfg, p, tk, kp, vp, bt, ln, si, so))
+                   T.forward_decode_paged(cfg, p, tk, (kp, vp), bt, ln, si,
+                                          so))
     compiled = _compile(
         step, params, _shape(one_chip, (BATCH, 1), jnp.int32), pool, pool,
         _shape(one_chip, (BATCH, BLOCKS_PER_SEQ), jnp.int32), vec, vec, vec)
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 2 * pool.size * 2  # both pools out
+
+
+def test_served_latent_decode_step_compiles(one_chip, no_compile_cache):
+    """The same for moonlight-16b-a3b's absorbed latent decode and its
+    dropless expert layer (8 of 64 experts held), cut to its dense layer
+    and one expert layer, at the benchmark's 64 rows of 128 blocks."""
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=2,
+                              experts_held=8)
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0)))
+    (width,) = cfg.cache_spec()["latent"]
+    pool = _shape(one_chip, (cfg.n_layers, POOL_BLOCKS, BLOCK, width),
+                  jnp.bfloat16)
+    vec = _shape(one_chip, (64,), jnp.int32)
+    step = jax.jit(lambda p, tk, lp, bt, ln, si, so, n:
+                   T.forward_decode_paged(cfg, p, tk, (lp,), bt, ln, si, so,
+                                          n))
+    compiled = _compile(
+        step, params, _shape(one_chip, (64, 1), jnp.int32), pool,
+        _shape(one_chip, (64, 128), jnp.int32), vec, vec, vec,
+        _shape(one_chip, (), jnp.int32))
+    assert compiled.memory_analysis().output_size_in_bytes >= pool.size * 2
+
+
+def test_flush_gather_of_latent_blocks_compiles(one_chip, no_compile_cache):
+    """The flusher's gather of 4 latent blocks from moonlight-16b-a3b's
+    whole pool (27 layers, 8,192 blocks) keeps no copy of the pool."""
+    from repro.kvcache.pool import _gather_slots
+    cfg = get_config("moonlight-16b-a3b")
+    pool = _shape(one_chip, (cfg.n_layers, 8192, BLOCK)
+                  + cfg.cache_spec()["latent"], jnp.bfloat16)
+    compiled = _compile(_gather_slots, (pool,),
+                        _shape(one_chip, (4,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_flush_gather_of_kv_blocks_compiles(one_chip, no_compile_cache):
+    """The same gather of 4 granite-3-8b K/V blocks (20 layers, the
+    benchmark's pool size) also keeps no copy of either pool."""
+    from repro.kvcache.pool import _gather_slots
+    pool = _shape(one_chip, (20, 2700, BLOCK, HKV, HD), jnp.bfloat16)
+    compiled = _compile(_gather_slots, (pool, pool),
+                        _shape(one_chip, (4,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 def test_sweep_grid_compiles(one_chip, no_compile_cache):
